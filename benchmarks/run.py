@@ -139,6 +139,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.record and args.check:
         ap.error("--record and --check are exclusive")
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     load_registry()
     from repro.bench import run_area

@@ -27,13 +27,12 @@ from repro.analysis.engine import (Analyzer, ModuleRule, ParsedModule,
                                    rule_ids, run_analysis)
 from repro.analysis.findings import AnalysisResult, Finding
 from repro.analysis.runtime import (RecompileWatchCallback, RecompileWatcher,
-                                    TransferGuardCallback, no_transfers,
-                                    transfer_guard_supported)
+                                    TransferGuardCallback, no_transfers)
 
 __all__ = [
     "Analyzer", "AnalysisResult", "Baseline", "DEFAULT_BASELINE",
     "Finding", "ModuleRule", "ParsedModule", "ProjectRule",
     "RecompileWatchCallback", "RecompileWatcher", "Rule",
     "TransferGuardCallback", "default_rules", "no_transfers",
-    "rule_ids", "run_analysis", "transfer_guard_supported",
+    "rule_ids", "run_analysis",
 ]

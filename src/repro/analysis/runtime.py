@@ -20,9 +20,6 @@ both at test time:
                                 the steady state must be transfer-free).
 ``RecompileWatchCallback``    — engine ``RoundCallback`` recording the
                                 compile count of every round.
-
-Both watchers degrade gracefully: ``supported`` flags whether the jax
-build exposes the hooks, and tests skip when it doesn't.
 """
 from __future__ import annotations
 
@@ -37,20 +34,9 @@ from repro.fl.callbacks import RoundCallback
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def transfer_guard_supported() -> bool:
-    return hasattr(jax, "transfer_guard")
-
-
 @contextlib.contextmanager
 def no_transfers(level: str = "disallow") -> Iterator[None]:
-    """Disallow implicit host<->device transfers inside the block.
-
-    Raises ``RuntimeError`` at enter when this jax build has no
-    ``transfer_guard`` (callers gate on ``transfer_guard_supported``).
-    """
-    if not transfer_guard_supported():
-        raise RuntimeError("jax.transfer_guard is not available in this "
-                           "jax build")
+    """Disallow implicit host<->device transfers inside the block."""
     with jax.transfer_guard(level):
         yield
 
@@ -69,19 +55,13 @@ def _on_duration_event(name: str, *args, **kwargs) -> None:
         _COMPILES += 1
 
 
-def _install_listener() -> bool:
-    """Register the global compile listener once; False when the jax
-    build has no monitoring hooks."""
+def _install_listener() -> None:
+    """Register the global compile listener once."""
     global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return True
-    mon = getattr(jax, "monitoring", None)
-    reg = getattr(mon, "register_event_duration_secs_listener", None)
-    if reg is None:
-        return False
-    reg(_on_duration_event)
-    _LISTENER_INSTALLED = True
-    return True
+    if not _LISTENER_INSTALLED:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _LISTENER_INSTALLED = True
 
 
 def compile_count() -> int:
@@ -104,7 +84,7 @@ class RecompileWatcher:
     """
 
     def __init__(self):
-        self.supported = _install_listener()
+        _install_listener()
         self.buckets: Dict[str, int] = {}
         self._start: Optional[int] = None
         self._last: int = 0
@@ -145,7 +125,6 @@ class RecompileWatchCallback(RoundCallback):
 
     def __init__(self):
         self.watcher = RecompileWatcher()
-        self.supported = self.watcher.supported
         self.per_round: Dict[int, int] = {}
         self._round: Optional[int] = None
 
@@ -188,13 +167,11 @@ class TransferGuardCallback(RoundCallback):
     def __init__(self, from_round: int = 2, level: str = "disallow"):
         self.from_round = from_round
         self.level = level
-        self.supported = transfer_guard_supported()
         self.guarded_rounds: List[int] = []
         self._stack: Optional[contextlib.ExitStack] = None
 
     def on_round_start(self, engine, rnd: int) -> None:
-        if (self.supported and self._stack is None
-                and rnd >= self.from_round):
+        if self._stack is None and rnd >= self.from_round:
             self._stack = contextlib.ExitStack()
             self._stack.enter_context(jax.transfer_guard(self.level))
         if self._stack is not None:

@@ -23,7 +23,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
-from jax.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var
+from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var
 
 #: primitives that are pure data movement: no flops charged.
 _MOVEMENT = {
@@ -35,7 +35,8 @@ _MOVEMENT = {
 #: host-boundary primitives: bytes crossing them count as transfers
 #: (and trip TRACE004 — nothing inside a steady-state jit should).
 TRANSFER_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "device_put",
 }
 
@@ -167,11 +168,11 @@ def iter_eqns(closed: ClosedJaxpr) -> Iterator[Tuple[JaxprEqn, int]]:
 
 
 def unwrap_pjit(closed: ClosedJaxpr) -> ClosedJaxpr:
-    """Peel the trivial outer pjit wrapper ``make_jaxpr(jit(f))``
+    """Peel the trivial outer ``jit`` wrapper ``make_jaxpr(jit(f))``
     produces, so liveness sees the real equations and donated argument
     indices line up with the inner jaxpr's invars."""
     while (len(closed.jaxpr.eqns) == 1
-           and closed.jaxpr.eqns[0].primitive.name == "pjit"
+           and closed.jaxpr.eqns[0].primitive.name == "jit"
            and list(closed.jaxpr.eqns[0].invars) == list(closed.jaxpr.invars)
            and list(closed.jaxpr.eqns[0].outvars)
            == list(closed.jaxpr.outvars)):

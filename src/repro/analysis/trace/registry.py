@@ -60,7 +60,7 @@ class EntryPoint:
     gated: bool = False
     #: the baseline-knobs twin whose peak defines bytes-per-memory-unit
     calibration: bool = False
-    #: trace under jax.experimental.enable_x64() (fixture entries)
+    #: trace under jax.enable_x64(True) (fixture entries)
     x64: bool = False
     #: TRACE rule ids intentionally suppressed for this entry
     allow: Tuple[str, ...] = ()
@@ -147,7 +147,7 @@ def trace_entry(entry: EntryPoint) -> TracedEntry:
     fn, args = entry.build()
 
     def ctx() -> Any:
-        return (jax.experimental.enable_x64() if entry.x64
+        return (jax.enable_x64(True) if entry.x64
                 else contextlib.nullcontext())
 
     with ctx():

@@ -11,10 +11,10 @@ the knob surface the Constraint API's ``wire_mb`` constraint steers.
 
 The FL loop calls ``compress_decompress`` (the server immediately
 dequantizes, so we model the *wire* format and keep the math in fp32).
-On TPU the quantize/top-k path is the fused Pallas kernel in
-``repro.kernels.wire``; on CPU (this container, and inside the FL
-simulation loop) the pure-jnp reference path is used —
-``repro.kernels.ops`` picks the backend.
+``repro.kernels.ops`` picks the backend: on TPU the quantize/top-k path
+runs the compiled Pallas kernels (``repro.kernels.quantize`` /
+``repro.kernels.wire``), and a kernel failure there is an error; off
+the TPU it runs their pure-jnp twins, which are bit-identical.
 """
 from __future__ import annotations
 

@@ -1,16 +1,15 @@
 """Tiny-Shakespeare-style char-level corpus.
 
-The container is offline, so ``load_corpus`` prefers a real
-``data/input.txt`` (the Karpathy file) if present and otherwise expands an
-embedded set of public-domain Shakespeare passages into a deterministic
-~600 KB corpus with the same dramatic-dialogue structure (speaker tags,
-blank lines, Early-Modern-English vocabulary). The paper's claims are
+``load_corpus`` reads the file it is given (e.g. the Karpathy
+``input.txt``) and otherwise expands an embedded set of public-domain
+Shakespeare passages into a deterministic ~600 KB corpus with the same
+dramatic-dialogue structure (speaker tags, blank lines,
+Early-Modern-English vocabulary). The paper's claims are
 about *resource-constraint satisfaction* — proxy-model-driven and
 corpus-independent — plus a relative val-loss gap, which survives the swap.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 import numpy as np
@@ -104,15 +103,12 @@ class CharDataset:
 
 def load_corpus(path: str | None = None, target_bytes: int = 600_000,
                 val_frac: float = 0.1) -> CharDataset:
-    text = None
-    for cand in ([path] if path else []) + [
-            os.path.join(os.path.dirname(__file__), "input.txt"),
-            "/root/repo/data/input.txt"]:
-        if cand and os.path.exists(cand):
-            with open(cand, "r", encoding="utf-8") as f:
-                text = f.read()
-            break
-    if text is None:
+    """The corpus at ``path`` if one is given, else the embedded one
+    expanded to ``target_bytes``; no other file is looked up."""
+    if path:
+        with open(path, "r", encoding="utf-8") as f:
+            text = f.read()
+    else:
         text = _expand(target_bytes)
     chars = sorted(set(text))
     stoi = {c: i for i, c in enumerate(chars)}

@@ -89,7 +89,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal=True, window=None, softcap=None,
                          scale=None, blk_q=DEFAULT_BLK_Q, blk_k=DEFAULT_BLK_K,
-                         interpret=True):
+                         interpret: bool):
     """q: (B,H,Sq,D); k,v: (B,KVH,Sk,D) -> (B,H,Sq,D)."""
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]

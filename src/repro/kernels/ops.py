@@ -1,9 +1,13 @@
 """jit'd public wrappers around the Pallas kernels, with backend dispatch.
 
-On TPU the compiled kernels run; on CPU (this container) the same kernel
-bodies execute in interpret mode for validation, and the hot paths used
-inside the FL simulation loop fall back to the pure-jnp reference (which
-XLA fuses well on CPU). ``FORCE_BACKEND`` lets tests pin either path.
+On TPU the compiled kernels run, and a kernel the chip's compiler
+refuses is an error: nothing falls back to the reference there. Off
+the TPU the hot paths use the pure-jnp twins in ``kernels/ref.py``
+(which XLA fuses well on CPU). ``FORCE_BACKEND`` pins either path;
+``"pallas"`` off the TPU runs the kernel bodies in interpret mode, which
+is how the CPU tests pin kernel-vs-ref bit equality. This module is the
+one place that chooses ``interpret``: every kernel takes it as a
+required keyword.
 """
 from __future__ import annotations
 
@@ -30,6 +34,11 @@ def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret() -> bool:
+    """Interpret the kernel bodies everywhere but on the TPU."""
+    return jax.default_backend() != "tpu"
+
+
 # ---------------------------------------------------------------------------
 # quantization
 # ---------------------------------------------------------------------------
@@ -38,6 +47,20 @@ def _use_pallas() -> bool:
 @functools.partial(jax.jit, static_argnames=("bits", "block", "topk"))
 def _qdq_ref(x, bits: int, block: int, topk):
     return ref.quantize_dequantize_ref(x, bits, block, topk=topk)
+
+
+def _pallas_wire(flat, bits: int, block: int, topk: Optional[int]):
+    """Flat f32 -> Pallas wire tuple ``(codes, scales, mask | None)``
+    over the input zero-padded to whole ``block * ROWS_PER_TILE`` tiles."""
+    pad = (-flat.shape[0]) % (block * qk.ROWS_PER_TILE)
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    blocks = flat.reshape(-1, block)
+    if topk is not None and topk < block:
+        return wk.quantize_topk_blocks(blocks, bits, topk,
+                                       interpret=_interpret())
+    codes, scales = qk.quantize_blocks(blocks, bits, interpret=_interpret())
+    return codes, scales, None
 
 
 def quantize_dequantize(x, *, bits: int, block: int = 256,
@@ -51,19 +74,9 @@ def quantize_dequantize(x, *, bits: int, block: int = 256,
         return _qdq_ref(x, bits, block, topk)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1).astype(jnp.float32)
-    n = flat.shape[0]
-    pad = (-n) % (block * qk.ROWS_PER_TILE)
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(-1, block)
-    interp = jax.default_backend() != "tpu"
-    if topk is not None and topk < block:
-        codes, scales, _ = wk.quantize_topk_blocks(blocks, bits, topk,
-                                                   interpret=interp)
-    else:
-        codes, scales = qk.quantize_blocks(blocks, bits, interpret=interp)
-    deq = qk.dequantize_blocks(codes, scales, interpret=interp)
-    return deq.reshape(-1)[:n].reshape(shape).astype(dtype)
+    codes, scales, _ = _pallas_wire(flat, bits, block, topk)
+    deq = qk.dequantize_blocks(codes, scales, interpret=_interpret())
+    return deq.reshape(-1)[:flat.shape[0]].reshape(shape).astype(dtype)
 
 
 _dequantize_blocks_ref_jit = jax.jit(ref.dequantize_blocks_ref)
@@ -79,8 +92,12 @@ def dequantize_blocks(codes, scales):
     """
     if not _use_pallas():
         return _dequantize_blocks_ref_jit(codes, scales)
-    interp = jax.default_backend() != "tpu"
-    return qk.dequantize_blocks(codes, scales, interpret=interp)
+    n = codes.shape[0]
+    pad = (-n) % qk.ROWS_PER_TILE
+    if pad:
+        codes = jnp.pad(codes, ((0, pad), (0, 0)))
+        scales = jnp.pad(scales, (0, pad))
+    return qk.dequantize_blocks(codes, scales, interpret=_interpret())[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "topk"))
@@ -112,17 +129,9 @@ def quantize_wire(x, *, bits: int, block: int = 256,
     if topk is not None and topk >= block:
         topk = None
     if _use_pallas():
-        pad = (-n) % (block * qk.ROWS_PER_TILE)
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        blocks = flat.reshape(-1, block)
-        interp = jax.default_backend() != "tpu"
-        if topk is not None:
-            codes, scales, mask = wk.quantize_topk_blocks(blocks, bits, topk,
-                                                          interpret=interp)
-            return (codes[:n_blocks], scales[:n_blocks], mask[:n_blocks], n)
-        codes, scales = qk.quantize_blocks(blocks, bits, interpret=interp)
-        return codes[:n_blocks], scales[:n_blocks], None, n
+        codes, scales, mask = _pallas_wire(flat, bits, block, topk)
+        return (codes[:n_blocks], scales[:n_blocks],
+                None if mask is None else mask[:n_blocks], n)
     pad = (-n) % block
     if pad:
         flat = jnp.pad(flat, (0, pad))
@@ -174,8 +183,7 @@ def masked_sum(hi, lo):
     if pad:
         hi = jnp.pad(hi, ((0, 0), (0, pad)))
         lo = jnp.pad(lo, ((0, 0), (0, pad)))
-    interp = jax.default_backend() != "tpu"
-    hi_s, lo_s = wk.masked_sum_limbs(hi, lo, interpret=interp)
+    hi_s, lo_s = wk.masked_sum_limbs(hi, lo, interpret=_interpret())
     return hi_s[:n], lo_s[:n]
 
 
@@ -215,10 +223,9 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    interp = jax.default_backend() != "tpu"
     out = fak.flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                    softcap=softcap, scale=scale,
-                                   interpret=interp)
+                                   interpret=_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -251,13 +258,13 @@ def trace_entry_points() -> list:
     from repro.analysis.trace.registry import EntryPoint
     path = "src/repro/kernels/ops.py"
     return [
-        EntryPoint(name="kernels.wire_dense", path=path, line=94,
+        EntryPoint(name="kernels.wire_dense", path=path, line=111,
                    build=_wire_build(8, None),
                    note="dense int8 wire tuple, 64k params"),
-        EntryPoint(name="kernels.wire_topk", path=path, line=94,
+        EntryPoint(name="kernels.wire_topk", path=path, line=111,
                    build=_wire_build(2, 64),
                    note="2-bit top-64 sparse wire tuple, 64k params"),
-        EntryPoint(name="kernels.masked_sum", path=path, line=157,
+        EntryPoint(name="kernels.masked_sum", path=path, line=166,
                    build=_masked_sum_build,
                    note="uint64-as-limbs cohort fold, C=8, n=4096"),
     ]

@@ -45,10 +45,9 @@ def topk_mask_ref(absx, k: int):
     row, largest magnitudes first, ties broken toward the lower index.
 
     Branch- and sort-free: rank_i = #{j : a_j > a_i} + #{j < i : a_j ==
-    a_i}; keep rank < k. O(block^2) comparisons, but every op is an
-    elementwise compare / reduction the VPU vectorizes — the same
-    expression runs inside the Pallas kernel, so the two paths agree
-    bit-for-bit.
+    a_i}; keep rank < k. O(block^2) plain comparisons, so this is the
+    oracle; the Pallas kernel selects the same set by bisection
+    (``kernels.wire._topk_keep``), so the two paths agree bit-for-bit.
     """
     rows, block = absx.shape
     if k >= block:

@@ -9,8 +9,10 @@ Results are written incrementally to results/dryrun/<arch>__<shape>__<mesh>.json
 (existing files are skipped — the matrix run is resumable).
 """
 # The 512 placeholder devices MUST be configured before any jax import —
-# jax locks the device count on first backend initialisation.
+# jax locks the device count on first backend initialisation. They are
+# CPU devices: the dry-run is compile-only and must never take a chip.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 _N_DEV = os.environ.get("DRYRUN_DEVICES", "512")
 os.environ["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={_N_DEV} "
                            + os.environ.get("XLA_FLAGS", ""))
@@ -27,7 +29,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.configs import INPUT_SHAPES, ARCH_IDS, get_config  # noqa: E402
 from repro.launch import specs as S                            # noqa: E402
 from repro.launch.mesh import (                                # noqa: E402
-    HBM_BW, ICI_BW, PEAK_FLOPS_BF16, activate_mesh, make_production_mesh)
+    HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh)
 from repro.launch.steps import (                               # noqa: E402
     make_decode_step, make_prefill_step, make_train_step)
 from repro.models import build                                 # noqa: E402
@@ -318,14 +320,14 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
                          in_shardings=(p_shard, o_shard, b_shard),
                          out_shardings=(p_shard, o_shard, repl),
                          donate_argnums=(0, 1))
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, opt_shapes, batch_specs)
     elif shape.kind == "prefill":
         batch_specs = S.input_specs(cfg, shape)
         b_shard = S.batch_shardings(mesh, batch_specs, shape)
         step = make_prefill_step(model, shape)
         jitted = jax.jit(step, in_shardings=(p_shard, b_shard))
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, batch_specs)
     else:  # decode
         c_specs = S.cache_specs(model, cfg, shape)
@@ -337,7 +339,7 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
                          in_shardings=(p_shard, c_shard, t_shard),
                          out_shardings=(repl, c_shard),
                          donate_argnums=(1,))
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, c_specs, tok_spec)
     return lowered, mesh, cfg, shape
 

@@ -25,23 +25,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def activate_mesh(mesh):
-    """Version-compat mesh activation for ``with`` blocks.
-
-    ``jax.sharding.set_mesh`` (newest JAX) and ``jax.sharding.use_mesh``
-    (0.5.x) install the mesh as the ambient sharding context; on older
-    releases (<= 0.4.x) neither exists and ``Mesh`` itself is the
-    context manager. All three enter/exit the same way, so the launch
-    path asks for whichever this JAX provides.
-    """
-    import jax.sharding
-    for name in ("set_mesh", "use_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh
-
-
 def batch_axes(mesh) -> tuple:
     """Mesh axes a global-batch dimension shards over."""
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)

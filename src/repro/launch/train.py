@@ -15,6 +15,7 @@ from repro.configs import get_config, get_fl_config
 from repro.data import load_corpus
 from repro.fl import (CheckpointCallback, FederatedEngine,
                       HistoryWriterCallback, LoggingCallback)
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build
 
 
@@ -34,6 +35,7 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="results/fl")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     ds = load_corpus()
     cfg = get_config(args.arch)

@@ -1,7 +1,6 @@
 """Runtime-sanitizer pins: the steady-state engine round loop runs with
 zero implicit host<->device transfers and zero jit recompiles after
-round 1 (repro.analysis.runtime). Tests skip gracefully when the jax
-build lacks the transfer-guard / monitoring hooks."""
+round 1 (repro.analysis.runtime)."""
 import dataclasses
 
 import jax
@@ -10,23 +9,17 @@ import numpy as np
 import pytest
 
 from repro.analysis.runtime import (RecompileWatchCallback, RecompileWatcher,
-                                    TransferGuardCallback, no_transfers,
-                                    transfer_guard_supported)
+                                    TransferGuardCallback, no_transfers)
 from repro.configs import get_config, get_fl_config
 from repro.data import load_corpus
 from repro.fl import FederatedEngine
 from repro.models import build
-
-needs_guard = pytest.mark.skipif(not transfer_guard_supported(),
-                                 reason="jax build has no transfer_guard")
-
 
 # ---------------------------------------------------------------------------
 # the primitives
 # ---------------------------------------------------------------------------
 
 
-@needs_guard
 def test_no_transfers_blocks_implicit_h2d():
     x = jnp.asarray(np.arange(4, dtype=np.float32))
     with pytest.raises(Exception):
@@ -34,7 +27,6 @@ def test_no_transfers_blocks_implicit_h2d():
             _ = x + 1               # Python scalar operand: implicit h2d
 
 
-@needs_guard
 def test_no_transfers_allows_staged_and_jitted_work():
     x = jnp.asarray(np.arange(4, dtype=np.float32))
     one = jnp.asarray(np.asarray(1.0, np.float32))
@@ -48,8 +40,6 @@ def test_no_transfers_allows_staged_and_jitted_work():
 
 def test_recompile_watcher_counts_cache_misses():
     w = RecompileWatcher()
-    if not w.supported:
-        pytest.skip("jax build has no monitoring hooks")
 
     @jax.jit
     def g(a):
@@ -95,7 +85,6 @@ def tiny_run():
     return result, guard, watch
 
 
-@needs_guard
 def test_engine_steady_state_is_transfer_free(tiny_run):
     """Rounds >= 2 run under jax.transfer_guard("disallow"): the round
     loop finishing at all IS the assertion — any implicit transfer in
@@ -110,8 +99,6 @@ def test_engine_zero_recompiles_after_round_one(tiny_run):
     from round 2 on the same executables must be reused — a drifting
     shape or static argument would show up as a backend compile."""
     _, _, watch = tiny_run
-    if not watch.supported:
-        pytest.skip("jax build has no monitoring hooks")
     assert watch.per_round.get(1, 0) > 0, "round 1 should compile"
     assert watch.steady_state_compiles(first_steady_round=2) == 0, (
         f"steady-state rounds recompiled: {watch.per_round}")

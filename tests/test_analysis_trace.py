@@ -82,9 +82,9 @@ def test_scan_flops_scale_with_length():
 def test_unwrap_pjit_exposes_body():
     f = jax.jit(lambda x: x * 2.0)
     closed = jax.make_jaxpr(f)(jax.ShapeDtypeStruct((8,), F32))
-    assert closed.jaxpr.eqns[0].primitive.name == "pjit"
+    assert closed.jaxpr.eqns[0].primitive.name == "jit"
     inner = unwrap_pjit(closed)
-    assert all(e.primitive.name != "pjit" for e in inner.jaxpr.eqns)
+    assert all(e.primitive.name != "jit" for e in inner.jaxpr.eqns)
 
 
 # ---------------------------------------------------------------------------

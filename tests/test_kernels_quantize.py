@@ -117,3 +117,15 @@ if given is not None:
         # idempotence: quantizing the dequantized signal is (nearly) stable
         z = ops.quantize_dequantize(y, bits=bits, block=256)
         assert float(jnp.max(jnp.abs(z - y))) <= 2 * bound * (1 + 1e-3) + 1e-5
+
+
+@pytest.mark.parametrize("n_blocks", [1, ROWS_PER_TILE + 3])
+def test_ops_dequantize_blocks_any_block_count(n_blocks, backend, rng):
+    """The dispatch pads to whole kernel tiles and strips the pad: the
+    wire tuple's ceil(n / block) blocks decode on either backend."""
+    x = jnp.asarray(rng.normal(size=(n_blocks * 256,)).astype(np.float32))
+    codes, scales, _, _ = ops.quantize_wire(x, bits=8)
+    got = np.asarray(ops.dequantize_blocks(codes, scales))
+    assert got.shape == (n_blocks, 256)
+    np.testing.assert_array_equal(
+        got, np.asarray(ref.dequantize_blocks_ref(codes, scales)))

@@ -93,3 +93,22 @@ def test_sharding_recipe_divisibility():
             assert n_sharded > 0, f"{arch}: nothing sharded"
     finally:
         S.NamedSharding = captured_orig
+
+
+@pytest.mark.parametrize("env_dir", [None, "/srv/jax-cache"])
+def test_compile_cache_placement(env_dir, monkeypatch):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, and nowhere
+    else; unset, it goes to the fixed <checkout>/.jax_cache."""
+    from repro.launch import compile_cache as cc
+    if env_dir is None:
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(cc.ENV_VAR, env_dir)
+    updates = {}
+    monkeypatch.setattr(cc.jax.config, "update",
+                        lambda name, value: updates.__setitem__(name, value))
+    want = env_dir or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    assert cc.setup_compile_cache() == want
+    assert updates["jax_compilation_cache_dir"] == want

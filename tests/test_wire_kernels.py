@@ -55,6 +55,27 @@ def test_quantize_topk_kernel_matches_ref(rng):
         np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-6)
 
 
+@pytest.mark.parametrize("k", [1, 7, 64, BLOCK - 1])
+def test_quantize_topk_kernel_ties_match_ref(k, rng):
+    """The kernel selects by bisection, the oracle by pairwise rank:
+    they keep the same set on tie-heavy blocks — few distinct
+    magnitudes, +/- pairs of one magnitude, all-zero and constant
+    blocks, and magnitudes spanning denormals to near-overflow."""
+    x = rng.integers(-3, 4, size=(ROWS_PER_TILE * 2, BLOCK)) \
+        .astype(np.float32)
+    x[1] = 0.0
+    x[2] = 5.0
+    x[3, 1::2] = -x[3, ::2]
+    x[4] = rng.choice(np.float32([1e-45, 1e-38, 1.0, 3e38]), size=BLOCK)
+    x = jnp.asarray(x)
+    ck, sk, mk = wk.quantize_topk_blocks(x, 2, k, interpret=True)
+    cr, sr, mr = ref.quantize_topk_blocks_ref(x, 2, k)
+    np.testing.assert_array_equal(np.asarray(mk), np.asarray(mr))
+    np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
+    np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
+    assert np.asarray(mk).sum(axis=1).tolist() == [k] * x.shape[0]
+
+
 def test_sparse_roundtrip_properties(backend, rng):
     """Dropped coordinates come back exactly 0.0, survivors obey the
     dense mid-tread bound (the scale is the dense absmax), and k=block
