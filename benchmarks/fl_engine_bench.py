@@ -76,10 +76,11 @@ def _setup(params):
     return build(cfg), fl, ds
 
 
-def _round_mean_us(timing, rounds):
-    """Mean round seconds as a pseudo-TimingStats (first round dropped
-    as compile when more than one was timed), in microseconds."""
-    seconds = timing.round_seconds[1:] or timing.round_seconds
+def _round_mean_us(history):
+    """Mean ``RoundRecord.seconds`` as a pseudo-TimingStats (first round
+    dropped as compile when more than one was timed), in microseconds."""
+    seconds = [r.seconds for r in history]
+    seconds = seconds[1:] or seconds
     mean = sum(seconds) / len(seconds)
     lo, hi = min(seconds), max(seconds)
     return TimingStats(median_us=mean * 1e6, iqr_us=(hi - lo) * 1e6,
@@ -151,7 +152,7 @@ def executor_bench(params):
                 "stragglers, mean round time incl. retraces")
 def dynamics_bench(params):
     from repro.fl import (DeadlineStragglers, FederatedEngine, FleetDynamics,
-                          FullParticipation, TimingCallback, UniformSampler)
+                          FullParticipation, UniformSampler)
 
     model, fl, ds = _setup(params)
     fl_bench = fl.replace(rounds=params["rounds"], eval_batches=1,
@@ -166,12 +167,9 @@ def dynamics_bench(params):
     }
     out = {"context": {}}
     for name, dyn in scenarios.items():
-        timing = TimingCallback()
         res = FederatedEngine(model, fl_bench, ds, strategy="cafl",
-                              executor="batched", dynamics=dyn,
-                              callbacks=[timing]).run()
-        out[f"{name}_round_mean_us"] = _round_mean_us(timing,
-                                                      fl_bench.rounds)
+                              executor="batched", dynamics=dyn).run()
+        out[f"{name}_round_mean_us"] = _round_mean_us(res.history)
         parts = sum(len(r.participants) for r in res.history)
         drops = sum(len(r.dropped) for r in res.history)
         out["context"][name] = f"{parts}reported+{drops}dropped,incl-retraces"
@@ -196,8 +194,7 @@ def dynamics_bench(params):
                 "rounds-to-target-loss (miss records as rounds+1)")
 def aggregator_bench(params):
     from repro.fl import (DeadlineStragglers, FedBuffAggregator,
-                          FederatedEngine, FleetDynamics, TimingCallback,
-                          UniformSampler)
+                          FederatedEngine, FleetDynamics, UniformSampler)
 
     model, fl, ds = _setup(params)
     fl_bench = fl.replace(rounds=params["rounds"], eval_batches=1,
@@ -214,13 +211,11 @@ def aggregator_bench(params):
     for name, agg in (("sync", "sync"),
                       ("fedbuff",
                        FedBuffAggregator(buffer_size=params["buffer_size"]))):
-        timing = TimingCallback()
         res = FederatedEngine(model, fl_bench, ds, strategy="fedavg",
                               executor="batched", dynamics=dyn(),
-                              aggregator=agg, callbacks=[timing]).run()
+                              aggregator=agg).run()
         runs[name] = res
-        out[f"{name}_round_mean_us"] = _round_mean_us(timing,
-                                                      fl_bench.rounds)
+        out[f"{name}_round_mean_us"] = _round_mean_us(res.history)
         applied = sum(r.reports_applied for r in res.history)
         late = sum(len(r.late_arrivals) for r in res.history)
         out["context"][name] = f"{applied}applied({late}late)"

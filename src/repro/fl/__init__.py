@@ -30,7 +30,7 @@ from repro.fl.aggregator import (  # noqa: F401
 )
 from repro.fl.callbacks import (  # noqa: F401
     CheckpointCallback, HistoryWriterCallback, LoggingCallback,
-    RoundCallback, TimingCallback,
+    RoundCallback,
 )
 from repro.fl.clock import (  # noqa: F401
     TIME_MODES, EventQueue, KnobRoundTime, RoundTimeModel, SimClock,

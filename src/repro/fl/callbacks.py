@@ -1,17 +1,15 @@
 """Round callbacks: side effects hooked out of the engine loop.
 
 The seed hardcoded ``log=print`` into ``run_federated``; everything
-observational (logging, checkpointing, history export, benchmark
-timing) is now a ``RoundCallback`` so the engine itself stays pure
-control flow.
+observational (logging, checkpointing, history export) is now a
+``RoundCallback`` so the engine itself stays pure control flow.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import os
-import time
-from typing import Callable, List, Optional
+from typing import Callable
 
 
 class RoundCallback:
@@ -126,22 +124,3 @@ class HistoryWriterCallback(RoundCallback):
         with open(self.path, "w") as f:
             json.dump(payload, f, indent=1)
 
-
-class TimingCallback(RoundCallback):
-    """Benchmark capture: wall-clock per round (excluding eval if the
-    engine reports it) for the executor micro-benchmarks."""
-
-    def __init__(self):
-        self.round_seconds: List[float] = []
-        self.total_seconds: Optional[float] = None
-        self._t0 = None
-
-    def on_train_start(self, engine) -> None:
-        self._t0 = time.time()
-
-    def on_round_end(self, engine, record) -> None:
-        self.round_seconds.append(record.seconds)
-
-    def on_train_end(self, engine, result) -> None:
-        if self._t0 is not None:
-            self.total_seconds = time.time() - self._t0

@@ -56,6 +56,11 @@ The engine runs in one of two *time modes* (``repro.fl.clock``):
                             the fixed round count with a simulated-
                             seconds budget.
 
+While a profiler trace records, each round is a host span
+(``repro.fl.spans``): ``round`` around its body and, inside it,
+``eval``, ``compose``, ``execute`` (the executor), ``report``,
+``aggregate``, ``accounting`` and ``dual_update``.
+
 ``repro.core.server.run_federated`` is a thin wrapper over this class
 that preserves the seed API exactly.
 """
@@ -76,6 +81,7 @@ from repro.core.resources import ResourceModel, calibrate
 from repro.core.server import FLResult, RoundRecord, make_eval_fn
 from repro.data.federated import FederatedData
 from repro.data.shakespeare import CharDataset
+from repro.fl import spans
 from repro.fl.aggregator import (Aggregator, ClientReport, ServerUpdate,
                                  canonical_order, make_aggregator)
 from repro.fl.callbacks import RoundCallback
@@ -279,258 +285,272 @@ class FederatedEngine:
                     and clock.now >= horizon_seconds:
                 break
             t += 1
-            t0 = time.time()
-            round_start = clock.now
-            self._emit("on_round_start", t)
-            val_loss = evaluate(params)
+            with spans.span("round", rnd=t):
+                t0 = time.time()
+                round_start = clock.now
+                self._emit("on_round_start", t)
+                with spans.span("eval"):
+                    val_loss = evaluate(params)
 
-            # --- round composition: gate, sample, deadline -------------
-            if wall:
-                roster = ([ci for ci in fleet if ci.client_id not in busy]
-                          if busy else fleet)
-            else:
-                # sorted: dict order here is insertion (= past delivery)
-                # order; expiry must not depend on it
-                for cid in sorted(c for c, due in busy_until.items()
-                                  if due < t):
-                    del busy_until[cid]
-                roster = ([ci for ci in fleet
-                           if ci.client_id not in busy_until]
-                          if busy_until else fleet)
-            avail, clients = dynamics.compose(
-                t, roster, rng, self.strategy.duals_snapshot())
-            base_knobs = self.strategy.configure_round(t, clients)
-            knobs = dynamics.adjust_knobs(clients, base_knobs)
-            surv_idx, drop_idx, times = dynamics.finish(t, clients, knobs,
-                                                        rng)
-            # the deadline in force DURING this round (a deadline-aware
-            # knob policy may widen it in observe_round, which must only
-            # affect the next round's duration)
-            deadline = getattr(dynamics.stragglers, "deadline", None)
-            # deadline-missers split into late (report still arrives,
-            # if the aggregator takes it and the run is still going at
-            # delivery time) vs lost (discarded for good: no arrival
-            # clock, a barrier aggregator, or due past the horizon —
-            # work the simulation would pay for but could never apply)
-            late_idx: List[int] = []
-            lost_idx: List[int] = []
-            due_round: Dict[int, int] = {}
-            if wall:
-                # a late report lands at its absolute arrival time; it
-                # is lost only when that time is past the horizon (with
-                # a round-count budget the end time is unknown, so the
-                # report stays in flight and undelivered leftovers are
-                # counted lost at run end)
-                for i in drop_idx:
-                    if agg.accepts_late and times and (
-                            horizon_seconds is None
-                            or round_start + times[i] <= horizon_seconds):
-                        late_idx.append(i)
+                # --- round composition: gate, sample, deadline ---------
+                with spans.span("compose"):
+                    if wall:
+                        roster = ([ci for ci in fleet
+                                   if ci.client_id not in busy]
+                                  if busy else fleet)
                     else:
-                        lost_idx.append(i)
-            else:
-                for i in drop_idx:
-                    delay = (dynamics.stragglers.late_rounds(times[i])
-                             if agg.accepts_late and times else None)
-                    if delay is not None and t + delay <= rounds:
-                        late_idx.append(i)
-                        due_round[i] = t + delay
+                        # sorted: dict order here is insertion (= past
+                        # delivery) order; expiry must not depend on it
+                        for cid in sorted(c for c, due in busy_until.items()
+                                          if due < t):
+                            del busy_until[cid]
+                        roster = ([ci for ci in fleet
+                                   if ci.client_id not in busy_until]
+                                  if busy_until else fleet)
+                    avail, clients = dynamics.compose(
+                        t, roster, rng, self.strategy.duals_snapshot())
+                    base_knobs = self.strategy.configure_round(t, clients)
+                    knobs = dynamics.adjust_knobs(clients, base_knobs)
+                    surv_idx, drop_idx, times = dynamics.finish(
+                        t, clients, knobs, rng)
+                    # the deadline in force DURING this round (a
+                    # deadline-aware knob policy may widen it in
+                    # observe_round, which must only affect the next
+                    # round's duration)
+                    deadline = getattr(dynamics.stragglers, "deadline", None)
+                    # deadline-missers split into late (report still arrives,
+                    # if the aggregator takes it and the run is still going at
+                    # delivery time) vs lost (discarded for good: no arrival
+                    # clock, a barrier aggregator, or due past the horizon —
+                    # work the simulation would pay for but could never apply)
+                    late_idx: List[int] = []
+                    lost_idx: List[int] = []
+                    due_round: Dict[int, int] = {}
+                    if wall:
+                        # a late report lands at its absolute arrival time; it
+                        # is lost only when that time is past the horizon (with
+                        # a round-count budget the end time is unknown, so the
+                        # report stays in flight and undelivered leftovers are
+                        # counted lost at run end)
+                        for i in drop_idx:
+                            if agg.accepts_late and times and (
+                                    horizon_seconds is None
+                                    or round_start + times[i]
+                                    <= horizon_seconds):
+                                late_idx.append(i)
+                            else:
+                                lost_idx.append(i)
                     else:
-                        lost_idx.append(i)
-            survivors = [clients[i] for i in surv_idx]
-            plan = RoundPlan(
-                round=t,
-                available=tuple(ci.client_id for ci in avail),
-                sampled=tuple(ci.client_id for ci in clients),
-                survivors=tuple(ci.client_id for ci in survivors),
-                dropped=tuple(clients[i].client_id for i in drop_idx),
-                times=tuple(times),
-                late=tuple(clients[i].client_id for i in late_idx))
-            self._emit("on_round_composed", plan)
-            if lost_idx:
-                self.strategy.on_dropout([clients[i] for i in lost_idx])
-            agg.begin_round(t, clients)
+                        for i in drop_idx:
+                            delay = (dynamics.stragglers.late_rounds(times[i])
+                                     if agg.accepts_late and times else None)
+                            if delay is not None and t + delay <= rounds:
+                                late_idx.append(i)
+                                due_round[i] = t + delay
+                            else:
+                                lost_idx.append(i)
+                    survivors = [clients[i] for i in surv_idx]
+                    plan = RoundPlan(
+                        round=t,
+                        available=tuple(ci.client_id for ci in avail),
+                        sampled=tuple(ci.client_id for ci in clients),
+                        survivors=tuple(ci.client_id for ci in survivors),
+                        dropped=tuple(clients[i].client_id for i in drop_idx),
+                        times=tuple(times),
+                        late=tuple(clients[i].client_id for i in late_idx))
+                self._emit("on_round_composed", plan)
+                if lost_idx:
+                    self.strategy.on_dropout([clients[i] for i in lost_idx])
+                agg.begin_round(t, clients)
 
-            # --- LocalTrain: survivors report now, late clients'
-            # reports are queued for the round their clock lands in ----
-            exec_idx = list(surv_idx) + late_idx
-            outs = (executor.run_round(
-                params, [(clients[i], knobs[i]) for i in exec_idx])
-                if exec_idx else [])
-            reports = {
-                i: self._report(clients[i], knobs[i], base_knobs[i], o, t,
-                                times[i] if times else 0.0)
-                for i, o in zip(exec_idx, outs)}
-            if not wall:
-                for i in late_idx:
-                    pending.setdefault(due_round[i], []).append(reports[i])
-                    busy_until[clients[i].client_id] = due_round[i]
+                # --- LocalTrain: survivors report now, late clients'
+                # reports are queued for the round their clock lands in ----
+                exec_idx = list(surv_idx) + late_idx
+                with spans.span("execute"):
+                    outs = (executor.run_round(
+                        params, [(clients[i], knobs[i]) for i in exec_idx])
+                        if exec_idx else [])
+                with spans.span("report"):
+                    reports = {
+                        i: self._report(clients[i], knobs[i], base_knobs[i],
+                                        o, t, times[i] if times else 0.0)
+                        for i, o in zip(exec_idx, outs)}
+                if not wall:
+                    for i in late_idx:
+                        pending.setdefault(due_round[i], []).append(reports[i])
+                        busy_until[clients[i].client_id] = due_round[i]
 
-            # --- deliver reports; the aggregator decides when they
-            # become server updates ------------------------------------
-            # the barrier's duration: min(deadline, slowest survivor)
-            # under a straggler clock, the knob-derived cohort time
-            # otherwise (see RoundTimeModel)
-            base_dur = rtm.round_seconds(clients, knobs, times, surv_idx,
-                                         deadline)
-            if wall and base_dur <= 0.0:
-                # a custom model returning non-positive durations would
-                # spin the horizon loop into the round backstop and
-                # return a normal-looking result well short of the
-                # horizon — fail loudly instead (KnobRoundTime enforces
-                # this itself via its idle floor)
-                raise ValueError(
-                    f"{type(rtm).__name__}.round_seconds returned "
-                    f"{base_dur!r}; wall-clock rounds need positive "
-                    f"durations")
-            applied: List[ServerUpdate] = []
+                # --- deliver reports; the aggregator decides when they
+                # become server updates ------------------------------------
+                # the barrier's duration: min(deadline, slowest survivor)
+                # under a straggler clock, the knob-derived cohort time
+                # otherwise (see RoundTimeModel)
+                base_dur = rtm.round_seconds(clients, knobs, times, surv_idx,
+                                             deadline)
+                if wall and base_dur <= 0.0:
+                    # a custom model returning non-positive durations would
+                    # spin the horizon loop into the round backstop and
+                    # return a normal-looking result well short of the
+                    # horizon — fail loudly instead (KnobRoundTime enforces
+                    # this itself via its idle floor)
+                    raise ValueError(
+                        f"{type(rtm).__name__}.round_seconds returned "
+                        f"{base_dur!r}; wall-clock rounds need positive "
+                        f"durations")
+                applied: List[ServerUpdate] = []
 
-            def _apply(update, params):
-                params = aggregation.apply_delta(params, update.delta)
-                self.params = params
-                applied.append(update)
-                self._emit("on_server_update", update)
-                return params
+                def _apply(update, params):
+                    params = aggregation.apply_delta(params, update.delta)
+                    self.params = params
+                    applied.append(update)
+                    self._emit("on_server_update", update)
+                    return params
 
-            if wall:
-                round_end_cap = round_start + base_dur
-                # earlier rounds' in-flight reports landing inside this
-                # round's window — popped BEFORE this round's missers
-                # join the queue, so a deadline-misser can never be
-                # delivered in its own round (e.g. through the server-
-                # cost tail of the cap); like rounds mode, a miss is
-                # always at least one round late
-                due = pending_q.pop_until(round_end_cap)
-                for i in late_idx:
-                    pending_q.push(round_start + times[i], reports[i])
-                    busy.add(clients[i].client_id)
-                events = [pending_q.stamp(
-                    round_start + (times[i] if times
-                                   else rtm.client_seconds(clients[i],
-                                                           knobs[i])),
-                    reports[i]) for i in surv_idx]
-                events = sorted(events + due, key=lambda e: e.sort_key())
-                arrived = []
-                inbox: List[ClientReport] = []
-                round_end = round_end_cap
-                cut = None
-                for k, ev in enumerate(events):
-                    rep = ev.report
-                    clock.advance_to(ev.arrival,
-                                     f"deliver:c{rep.client.client_id}")
-                    if rep.round_trained < t:
-                        arrived.append(rep)
-                    busy.discard(rep.client.client_id)
-                    rep.round_submitted = t
-                    rep.staleness = t - rep.round_trained
-                    inbox.append(rep)
-                    update = agg.submit(rep)
-                    if update is not None:
-                        params = _apply(update, params)
-                        if agg.applies_mid_round:
-                            # the buffer event completes this round:
-                            # deliveries after it belong to the next
-                            # round's inbox (their owners stay busy)
-                            round_end = ev.arrival + server_cost
-                            cut = k + 1
-                            break
-                if cut is not None:
-                    for ev in events[cut:]:
-                        pending_q.push_event(ev)
-                        busy.add(ev.report.client.client_id)
-                else:
-                    update = agg.flush(t)
-                    if update is not None:
-                        params = _apply(update, params)
-                clock.advance_to(round_end, f"round_end:{t}")
-            else:
-                arrived = sorted(pending.pop(t, ()),
-                                 key=lambda r: (r.round_trained,
-                                                r.arrival_time))
-                inbox = arrived + [reports[i] for i in surv_idx]
-                for rep in inbox:
-                    rep.round_submitted = t
-                    rep.staleness = t - rep.round_trained
-                    update = agg.submit(rep)
-                    if update is not None:
-                        params = _apply(update, params)
-                update = agg.flush(t)
-                if update is not None:
-                    params = _apply(update, params)
-                # pure accounting in rounds mode: the clock advances by
-                # the same barrier duration wall-clock mode would bill,
-                # so sim_time / round_seconds stay comparable across
-                # modes without touching the seed loop semantics
-                clock.advance_to(round_start + base_dur, f"round_end:{t}")
-            dynamics.settle(clients, base_knobs, knobs,
-                            list(surv_idx) + late_idx, lost_idx)
+                with spans.span("aggregate"):
+                    if wall:
+                        round_end_cap = round_start + base_dur
+                        # earlier rounds' in-flight reports landing inside this
+                        # round's window — popped BEFORE this round's missers
+                        # join the queue, so a deadline-misser can never be
+                        # delivered in its own round (e.g. through the server-
+                        # cost tail of the cap); like rounds mode, a miss is
+                        # always at least one round late
+                        due = pending_q.pop_until(round_end_cap)
+                        for i in late_idx:
+                            pending_q.push(round_start + times[i], reports[i])
+                            busy.add(clients[i].client_id)
+                        events = [pending_q.stamp(
+                            round_start + (times[i] if times
+                                           else rtm.client_seconds(clients[i],
+                                                                   knobs[i])),
+                            reports[i]) for i in surv_idx]
+                        events = sorted(events + due,
+                                        key=lambda e: e.sort_key())
+                        arrived = []
+                        inbox: List[ClientReport] = []
+                        round_end = round_end_cap
+                        cut = None
+                        for k, ev in enumerate(events):
+                            rep = ev.report
+                            clock.advance_to(
+                                ev.arrival, f"deliver:c{rep.client.client_id}")
+                            if rep.round_trained < t:
+                                arrived.append(rep)
+                            busy.discard(rep.client.client_id)
+                            rep.round_submitted = t
+                            rep.staleness = t - rep.round_trained
+                            inbox.append(rep)
+                            update = agg.submit(rep)
+                            if update is not None:
+                                params = _apply(update, params)
+                                if agg.applies_mid_round:
+                                    # the buffer event completes this round:
+                                    # deliveries after it belong to the next
+                                    # round's inbox (their owners stay busy)
+                                    round_end = ev.arrival + server_cost
+                                    cut = k + 1
+                                    break
+                        if cut is not None:
+                            for ev in events[cut:]:
+                                pending_q.push_event(ev)
+                                busy.add(ev.report.client.client_id)
+                        else:
+                            update = agg.flush(t)
+                            if update is not None:
+                                params = _apply(update, params)
+                        clock.advance_to(round_end, f"round_end:{t}")
+                    else:
+                        arrived = sorted(pending.pop(t, ()),
+                                         key=lambda r: (r.round_trained,
+                                                        r.arrival_time))
+                        inbox = arrived + [reports[i] for i in surv_idx]
+                        for rep in inbox:
+                            rep.round_submitted = t
+                            rep.staleness = t - rep.round_trained
+                            update = agg.submit(rep)
+                            if update is not None:
+                                params = _apply(update, params)
+                        update = agg.flush(t)
+                        if update is not None:
+                            params = _apply(update, params)
+                        # pure accounting in rounds mode: the clock advances by
+                        # the same barrier duration wall-clock mode would bill,
+                        # so sim_time / round_seconds stay comparable across
+                        # modes without touching the seed loop semantics
+                        clock.advance_to(round_start + base_dur,
+                                         f"round_end:{t}")
+                dynamics.settle(clients, base_knobs, knobs,
+                                list(surv_idx) + late_idx, lost_idx)
 
-            # --- constraint accounting over the reports delivered -----
-            # folded over the *canonical* report order, not the
-            # delivery order: the float means (and through them the
-            # dual trajectory) are a function of the report set, so a
-            # schedule permutation that only reorders simultaneous
-            # deliveries cannot move a single bit of the accounting.
-            # `inbox` itself keeps delivery order — participants /
-            # late_arrivals are schedule telemetry and record it.
-            stats = canonical_order(inbox)
-            usages = [cset.measure(rep) for rep in stats]
-            if stats:
-                usage = {n: float(np.mean([u[n] for u in usages]))
-                         for n in cset.names}
-                train_loss = float(np.mean([rep.train_loss
-                                            for rep in stats]))
-                wire_mb = float(np.mean([rep.wire_mb_actual
-                                         for rep in stats]))
-                energy = float(np.mean([rep.energy_true for rep in stats]))
-            else:               # everyone dropped / nobody reachable
-                usage = cset.zero_usage()
-                train_loss = wire_mb = energy = 0.0
-            ratios = cset.ratios(usage, fl.budgets)
-            duals_by_profile = self.strategy.update_state(
-                usages, [rep.client for rep in stats])
-            creports = self.strategy.constraint_reports()
-            if creports:
-                self._emit("on_dual_update", t, creports)
-            # round telemetry back to the strategy (knob policies may
-            # steer server-side knobs, e.g. widen the straggler
-            # deadline, before the next round is composed)
-            self.strategy.observe_round(plan, inbox, dynamics)
+                # --- constraint accounting over the reports delivered -----
+                # folded over the *canonical* report order, not the
+                # delivery order: the float means (and through them the
+                # dual trajectory) are a function of the report set, so a
+                # schedule permutation that only reorders simultaneous
+                # deliveries cannot move a single bit of the accounting.
+                # `inbox` itself keeps delivery order — participants /
+                # late_arrivals are schedule telemetry and record it.
+                with spans.span("accounting"):
+                    stats = canonical_order(inbox)
+                    usages = [cset.measure(rep) for rep in stats]
+                    if stats:
+                        usage = {n: float(np.mean([u[n] for u in usages]))
+                                 for n in cset.names}
+                        train_loss = float(np.mean([rep.train_loss
+                                                    for rep in stats]))
+                        wire_mb = float(np.mean([rep.wire_mb_actual
+                                                 for rep in stats]))
+                        energy = float(np.mean([rep.energy_true
+                                                for rep in stats]))
+                    else:               # everyone dropped / nobody reachable
+                        usage = cset.zero_usage()
+                        train_loss = wire_mb = energy = 0.0
+                    ratios = cset.ratios(usage, fl.budgets)
+                with spans.span("dual_update"):
+                    duals_by_profile = self.strategy.update_state(
+                        usages, [rep.client for rep in stats])
+                    creports = self.strategy.constraint_reports()
+                if creports:
+                    self._emit("on_dual_update", t, creports)
+                # round telemetry back to the strategy (knob policies may
+                # steer server-side knobs, e.g. widen the straggler
+                # deadline, before the next round is composed)
+                self.strategy.observe_round(plan, inbox, dynamics)
 
-            # record the strategy's policy knobs, not any one client's
-            # private carry boost (that stays visible via RoundPlan)
-            duals_rec = _default_duals(duals_by_profile, cset.names)
-            record = RoundRecord(
-                round=t, val_loss=val_loss,
-                knobs=base_knobs[0].as_dict() if base_knobs else {},
-                usage=usage, ratios=ratios,
-                duals=duals_rec,
-                constraints={n: {"ratio": ratios[n],
-                                 "lam": duals_rec.get(n, 0.0),
-                                 "violated": ratios[n] > 1.0}
-                             for n in cset.names},
-                train_loss=train_loss,
-                wire_mb_actual=wire_mb,
-                energy_true=energy,
-                seconds=time.time() - t0,
-                sim_time=clock.now,
-                round_seconds=clock.now - round_start,
-                per_profile=_per_profile_record(
-                    [rep.client for rep in stats],
-                    [rep.policy_knobs for rep in stats], usages,
-                    duals_by_profile, cset)
-                if heterogeneous and stats else {},
-                participants=[rep.client.client_id for rep in inbox],
-                dropped=[clients[i].client_id for i in lost_idx],
-                num_available=len(avail),
-                updates_applied=len(applied),
-                reports_applied=sum(len(u.reports) for u in applied),
-                mean_staleness=(float(np.mean([rep.staleness
-                                               for rep in stats]))
-                                if stats else 0.0),
-                late_arrivals=[rep.client.client_id for rep in arrived])
-            result.history.append(record)
-            self._emit("on_round_end", record)
+                # record the strategy's policy knobs, not any one client's
+                # private carry boost (that stays visible via RoundPlan)
+                duals_rec = _default_duals(duals_by_profile, cset.names)
+                record = RoundRecord(
+                    round=t, val_loss=val_loss,
+                    knobs=base_knobs[0].as_dict() if base_knobs else {},
+                    usage=usage, ratios=ratios,
+                    duals=duals_rec,
+                    constraints={n: {"ratio": ratios[n],
+                                     "lam": duals_rec.get(n, 0.0),
+                                     "violated": ratios[n] > 1.0}
+                                 for n in cset.names},
+                    train_loss=train_loss,
+                    wire_mb_actual=wire_mb,
+                    energy_true=energy,
+                    seconds=time.time() - t0,
+                    sim_time=clock.now,
+                    round_seconds=clock.now - round_start,
+                    per_profile=_per_profile_record(
+                        [rep.client for rep in stats],
+                        [rep.policy_knobs for rep in stats], usages,
+                        duals_by_profile, cset)
+                    if heterogeneous and stats else {},
+                    participants=[rep.client.client_id for rep in inbox],
+                    dropped=[clients[i].client_id for i in lost_idx],
+                    num_available=len(avail),
+                    updates_applied=len(applied),
+                    reports_applied=sum(len(u.reports) for u in applied),
+                    mean_staleness=(float(np.mean([rep.staleness
+                                                   for rep in stats]))
+                                    if stats else 0.0),
+                    late_arrivals=[rep.client.client_id for rep in arrived])
+                result.history.append(record)
+                self._emit("on_round_end", record)
 
         # drain whatever the policy still buffers (e.g. FedBuff's
         # partial buffer): those clients were executed, accounted and

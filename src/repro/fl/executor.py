@@ -10,7 +10,10 @@ training as ONE jitted call: ``vmap`` over clients of a
 ``lax.scan`` over local steps of a ``lax.scan`` over grad-accum
 microbatches. That removes the per-client Python dispatch and every
 intermediate host sync — the only transfer per group is the stacked
-deltas and losses coming back.
+deltas and losses coming back. Under the profiler its host work is
+spans inside the engine's ``execute`` (``repro.fl.spans``): ``stage``
+and ``local_train_wait`` per group, ``unstack``, ``wire`` and
+``wire_bytes`` per client; ``localtrain_calls`` counts the launches.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import numpy as np
 from repro.core.client import (ClientResult, ClientRunner,
                                _masked_wire_mb, apply_masked_update)
 from repro.core.policy import Knobs
+from repro.fl import spans
 from repro.fl.device import ClientInfo
 
 Assignment = Tuple[ClientInfo, Knobs]
@@ -119,18 +123,23 @@ class BatchedExecutor(ClientExecutor):
         for kn, idxs in groups.items():
             cids = [assignments[i][0].client_id for i in idxs]
             mask, active = self.runner.mask_for(params, kn.k)
-            batches = self._stack_batches(cids, kn)
+            with spans.span("stage"):
+                batches = self._stack_batches(cids, kn)
+            spans.count("localtrain_calls")
             deltas, losses = self._batched(params, mask, batches)
-            losses = np.asarray(losses)
+            with spans.span("local_train_wait"):
+                losses = np.asarray(losses)
             topk = self.runner.fl.wire_topk
             for row, i in enumerate(idxs):
-                raw = jax.tree.map(lambda l, r=row: l[r], deltas)
-                delta = _compress(raw, mask, kn.q, topk=topk)
+                with spans.span("unstack"):
+                    raw = jax.tree.map(lambda l, r=row: l[r], deltas)
+                with spans.span("wire"):
+                    delta = _compress(raw, mask, kn.q, topk=topk)
+                with spans.span("wire_bytes"):
+                    wire_mb = _masked_wire_mb(delta, mask, kn.q, topk=topk)
                 results[i] = ClientResult(
                     client_id=cids[row], delta=delta, params_active=active,
-                    train_loss=float(losses[row]),
-                    wire_mb_actual=_masked_wire_mb(delta, mask, kn.q,
-                                                   topk=topk))
+                    train_loss=float(losses[row]), wire_mb_actual=wire_mb)
         return results
 
 
@@ -167,7 +176,7 @@ def trace_entry_points() -> List[object]:
     from repro.analysis.trace.registry import EntryPoint
     return [EntryPoint(
         name="fl.executor_batched_round", path="src/repro/fl/executor.py",
-        line=58, build=_batched_round_build,
+        line=62, build=_batched_round_build,
         note="vmap(C=2) of scan(s=2) of scan(ga=1), b=4")]
 
 

@@ -7,7 +7,9 @@ the TPU the hot paths use the pure-jnp twins in ``kernels/ref.py``
 ``"pallas"`` off the TPU runs the kernel bodies in interpret mode, which
 is how the CPU tests pin kernel-vs-ref bit equality. This module is the
 one place that chooses ``interpret``: every kernel takes it as a
-required keyword.
+required keyword. While the profiler records, each wire-kernel launch
+(a quantize, a dequantize, or a ref twin's whole round trip) adds one
+to the round's ``wire_calls`` counter (``repro.fl.spans``).
 """
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.fl import spans
 from repro.kernels import ref
 from repro.kernels import quantize as qk
 from repro.kernels import wire as wk
@@ -56,6 +59,7 @@ def _pallas_wire(flat, bits: int, block: int, topk: Optional[int]):
     if pad:
         flat = jnp.pad(flat, (0, pad))
     blocks = flat.reshape(-1, block)
+    spans.count("wire_calls")
     if topk is not None and topk < block:
         return wk.quantize_topk_blocks(blocks, bits, topk,
                                        interpret=_interpret())
@@ -71,10 +75,12 @@ def quantize_dequantize(x, *, bits: int, block: int = 256,
     sparse wire format); dropped coordinates round-trip to exactly 0.0.
     """
     if not _use_pallas():
+        spans.count("wire_calls")
         return _qdq_ref(x, bits, block, topk)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1).astype(jnp.float32)
     codes, scales, _ = _pallas_wire(flat, bits, block, topk)
+    spans.count("wire_calls")
     deq = qk.dequantize_blocks(codes, scales, interpret=_interpret())
     return deq.reshape(-1)[:flat.shape[0]].reshape(shape).astype(dtype)
 
@@ -90,6 +96,7 @@ def dequantize_blocks(codes, scales):
     other kernel: the Pallas ``quantize.dequantize_blocks`` kernel on
     TPU, the pure-jnp ``dequantize_blocks_ref`` twin elsewhere.
     """
+    spans.count("wire_calls")
     if not _use_pallas():
         return _dequantize_blocks_ref_jit(codes, scales)
     n = codes.shape[0]
@@ -136,6 +143,7 @@ def quantize_wire(x, *, bits: int, block: int = 256,
     if pad:
         flat = jnp.pad(flat, (0, pad))
     blocks = flat.reshape(-1, block)
+    spans.count("wire_calls")
     codes, scales, mask = _quantize_wire_ref(blocks, bits, topk)
     return codes, scales, mask, n
 
@@ -258,13 +266,13 @@ def trace_entry_points() -> list:
     from repro.analysis.trace.registry import EntryPoint
     path = "src/repro/kernels/ops.py"
     return [
-        EntryPoint(name="kernels.wire_dense", path=path, line=111,
+        EntryPoint(name="kernels.wire_dense", path=path, line=118,
                    build=_wire_build(8, None),
                    note="dense int8 wire tuple, 64k params"),
-        EntryPoint(name="kernels.wire_topk", path=path, line=111,
+        EntryPoint(name="kernels.wire_topk", path=path, line=118,
                    build=_wire_build(2, 64),
                    note="2-bit top-64 sparse wire tuple, 64k params"),
-        EntryPoint(name="kernels.masked_sum", path=path, line=166,
+        EntryPoint(name="kernels.masked_sum", path=path, line=174,
                    build=_masked_sum_build,
                    note="uint64-as-limbs cohort fold, C=8, n=4096"),
     ]
