@@ -9,11 +9,14 @@ shapes), pre-samples every microbatch, and runs the whole group's local
 training as ONE jitted call: ``vmap`` over clients of a
 ``lax.scan`` over local steps of a ``lax.scan`` over grad-accum
 microbatches. That removes the per-client Python dispatch and every
-intermediate host sync — the only transfer per group is the stacked
-deltas and losses coming back. Under the profiler its host work is
-spans inside the engine's ``execute`` (``repro.fl.spans``): ``stage``
-and ``local_train_wait`` per group, ``unstack``, ``wire`` and
-``wire_bytes`` per client; ``localtrain_calls`` counts the launches.
+intermediate host sync. The group's wire round trip then runs over the
+stacked deltas on the device too: one pack, one quantize and one
+dequantize for the whole group, and one program that hands back the
+per-client trees. Under the profiler its host work is spans inside the
+engine's ``execute`` (``repro.fl.spans``), each once per knob group:
+``stage``, ``local_train_wait``, ``wire`` (pack and round trip; none
+at q=0), ``unstack`` (per-client trees and freeze mask) and
+``wire_bytes``; ``localtrain_calls`` counts the launches.
 """
 from __future__ import annotations
 
@@ -119,6 +122,7 @@ class BatchedExecutor(ClientExecutor):
         for idx, (_, kn) in enumerate(assignments):
             groups.setdefault(kn, []).append(idx)
 
+        topk = self.runner.fl.wire_topk
         results: List[ClientResult] = [None] * len(assignments)  # type: ignore
         for kn, idxs in groups.items():
             cids = [assignments[i][0].client_id for i in idxs]
@@ -129,26 +133,34 @@ class BatchedExecutor(ClientExecutor):
             deltas, losses = self._batched(params, mask, batches)
             with spans.span("local_train_wait"):
                 losses = np.asarray(losses)
-            topk = self.runner.fl.wire_topk
+            shipped = _compress(deltas, mask, kn.q, topk=topk)
+            del deltas
+            with spans.span("wire_bytes"):
+                # mask, q and topk are the group's: one count serves all
+                wire_mb = _masked_wire_mb(shipped[0], mask, kn.q, topk=topk)
             for row, i in enumerate(idxs):
-                with spans.span("unstack"):
-                    raw = jax.tree.map(lambda l, r=row: l[r], deltas)
-                with spans.span("wire"):
-                    delta = _compress(raw, mask, kn.q, topk=topk)
-                with spans.span("wire_bytes"):
-                    wire_mb = _masked_wire_mb(delta, mask, kn.q, topk=topk)
                 results[i] = ClientResult(
-                    client_id=cids[row], delta=delta, params_active=active,
-                    train_loss=float(losses[row]), wire_mb_actual=wire_mb)
+                    client_id=cids[row], delta=shipped[row],
+                    params_active=active, train_loss=float(losses[row]),
+                    wire_mb_actual=wire_mb)
         return results
 
 
-def _compress(raw_delta, mask, q: int, topk=None):
-    """Wire-compress an already-computed fp32 delta (the batched path
-    computes w - params on device; only the q/topk knobs remain)."""
-    from repro.core import compression, freezing
-    delta = compression.compress_decompress(raw_delta, q, topk=topk)
-    return freezing.apply_mask(delta, mask)
+def _compress(raw, mask, q: int, topk=None):
+    """Wire round trip of one knob group's stacked fp32 deltas (the
+    batched path computes w - params on device; only the q/topk knobs
+    remain) -> the C per-client trees as shipped, freeze-masked.
+    Consumes ``raw`` at q > 0 (``compress_decompress_stacked``)."""
+    from repro.core import compression
+    if q == 0:
+        with spans.span("unstack"):
+            return compression.unstack_masked(raw, mask)
+    like = jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype),
+                        raw)
+    with spans.span("wire"):
+        blocks = compression.compress_decompress_stacked(raw, q, topk=topk)
+    with spans.span("unstack"):
+        return compression.unpack_stacked(blocks, mask, like)
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +188,7 @@ def trace_entry_points() -> List[object]:
     from repro.analysis.trace.registry import EntryPoint
     return [EntryPoint(
         name="fl.executor_batched_round", path="src/repro/fl/executor.py",
-        line=62, build=_batched_round_build,
+        line=65, build=_batched_round_build,
         note="vmap(C=2) of scan(s=2) of scan(ga=1), b=4")]
 
 

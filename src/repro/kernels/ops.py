@@ -8,8 +8,9 @@ the TPU the hot paths use the pure-jnp twins in ``kernels/ref.py``
 is how the CPU tests pin kernel-vs-ref bit equality. This module is the
 one place that chooses ``interpret``: every kernel takes it as a
 required keyword. While the profiler records, each wire-kernel launch
-(a quantize, a dequantize, or a ref twin's whole round trip) adds one
-to the round's ``wire_calls`` counter (``repro.fl.spans``).
+(a quantize, a dequantize, or a ref twin's whole round trip, whether
+over one tensor or over a knob group's packed blocks) adds one to the
+round's ``wire_calls`` counter (``repro.fl.spans``).
 """
 from __future__ import annotations
 
@@ -52,19 +53,24 @@ def _qdq_ref(x, bits: int, block: int, topk):
     return ref.quantize_dequantize_ref(x, bits, block, topk=topk)
 
 
+def _pallas_quantize(blocks, bits: int, topk: Optional[int]):
+    """(n_blocks, block) f32, whole ``ROWS_PER_TILE`` tiles -> Pallas
+    wire tuple ``(codes, scales, mask | None)``: one kernel launch."""
+    spans.count("wire_calls")
+    if topk is not None and topk < blocks.shape[1]:
+        return wk.quantize_topk_blocks(blocks, bits, topk,
+                                       interpret=_interpret())
+    codes, scales = qk.quantize_blocks(blocks, bits, interpret=_interpret())
+    return codes, scales, None
+
+
 def _pallas_wire(flat, bits: int, block: int, topk: Optional[int]):
     """Flat f32 -> Pallas wire tuple ``(codes, scales, mask | None)``
     over the input zero-padded to whole ``block * ROWS_PER_TILE`` tiles."""
     pad = (-flat.shape[0]) % (block * qk.ROWS_PER_TILE)
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(-1, block)
-    spans.count("wire_calls")
-    if topk is not None and topk < block:
-        return wk.quantize_topk_blocks(blocks, bits, topk,
-                                       interpret=_interpret())
-    codes, scales = qk.quantize_blocks(blocks, bits, interpret=_interpret())
-    return codes, scales, None
+    return _pallas_quantize(flat.reshape(-1, block), bits, topk)
 
 
 def quantize_dequantize(x, *, bits: int, block: int = 256,
@@ -83,6 +89,31 @@ def quantize_dequantize(x, *, bits: int, block: int = 256,
     spans.count("wire_calls")
     deq = qk.dequantize_blocks(codes, scales, interpret=_interpret())
     return deq.reshape(-1)[:flat.shape[0]].reshape(shape).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "topk"))
+def _qdq_blocks_ref(blocks, bits: int, topk):
+    return ref.quantize_dequantize_blocks_ref(blocks, bits, topk=topk)
+
+
+def quantize_dequantize_blocks(blocks, *, bits: int,
+                               topk: Optional[int] = None):
+    """Wire round-trip of blocks already laid out for the kernels:
+    (n_blocks, block) f32 with ``n_blocks`` a whole number of
+    ``ROWS_PER_TILE`` tiles -> the dequantized (n_blocks, block) f32.
+
+    Every block is quantized on its own, so one call over many tensors'
+    blocks gives each block the bits a call per tensor gives it. Two
+    launches on the Pallas path (quantize, or the top-k quantize, then
+    dequantize, each its own jitted program), one ref-twin program
+    elsewhere.
+    """
+    if not _use_pallas():
+        spans.count("wire_calls")
+        return _qdq_blocks_ref(blocks, bits, topk)
+    codes, scales, _ = _pallas_quantize(blocks, bits, topk)
+    spans.count("wire_calls")
+    return qk.dequantize_blocks(codes, scales, interpret=_interpret())
 
 
 _dequantize_blocks_ref_jit = jax.jit(ref.dequantize_blocks_ref)
@@ -266,13 +297,13 @@ def trace_entry_points() -> list:
     from repro.analysis.trace.registry import EntryPoint
     path = "src/repro/kernels/ops.py"
     return [
-        EntryPoint(name="kernels.wire_dense", path=path, line=118,
+        EntryPoint(name="kernels.wire_dense", path=path, line=149,
                    build=_wire_build(8, None),
                    note="dense int8 wire tuple, 64k params"),
-        EntryPoint(name="kernels.wire_topk", path=path, line=118,
+        EntryPoint(name="kernels.wire_topk", path=path, line=149,
                    build=_wire_build(2, 64),
                    note="2-bit top-64 sparse wire tuple, 64k params"),
-        EntryPoint(name="kernels.masked_sum", path=path, line=174,
+        EntryPoint(name="kernels.masked_sum", path=path, line=205,
                    build=_masked_sum_build,
                    note="uint64-as-limbs cohort fold, C=8, n=4096"),
     ]

@@ -77,6 +77,15 @@ def quantize_topk_blocks_ref(x2d, bits: int, k: int):
     return codes.astype(jnp.int8), scales, keep.astype(jnp.int8)
 
 
+def quantize_dequantize_blocks_ref(blocks, bits: int, topk=None):
+    """(n_blocks, block) f32 -> wire round-trip, (n_blocks, block) f32."""
+    if topk is not None and topk < blocks.shape[1]:
+        codes, scales, _ = quantize_topk_blocks_ref(blocks, bits, topk)
+    else:
+        codes, scales = quantize_blocks_ref(blocks, bits)
+    return dequantize_blocks_ref(codes, scales)
+
+
 def quantize_dequantize_ref(x, bits: int, block: int = 256, topk=None):
     """Arbitrary-shape tensor -> wire round-trip, same shape/dtype."""
     shape, dtype = x.shape, x.dtype
@@ -85,12 +94,7 @@ def quantize_dequantize_ref(x, bits: int, block: int = 256, topk=None):
     pad = (-n) % block
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(-1, block)
-    if topk is not None and topk < block:
-        codes, scales, _ = quantize_topk_blocks_ref(blocks, bits, topk)
-    else:
-        codes, scales = quantize_blocks_ref(blocks, bits)
-    deq = dequantize_blocks_ref(codes, scales)
+    deq = quantize_dequantize_blocks_ref(flat.reshape(-1, block), bits, topk)
     return deq.reshape(-1)[:n].reshape(shape).astype(dtype)
 
 

@@ -102,3 +102,37 @@ def test_engine_zero_recompiles_after_round_one(tiny_run):
     assert watch.per_round.get(1, 0) > 0, "round 1 should compile"
     assert watch.steady_state_compiles(first_steady_round=2) == 0, (
         f"steady-state rounds recompiled: {watch.per_round}")
+
+
+
+@pytest.mark.parametrize("topk", [None, 64])
+def test_batched_wire_round_is_transfer_free(topk):
+    """Once warm, a batched round at q > 0 (LocalTrain, then the knob
+    group's stacked wire: pack, round trip, unpack) runs under the
+    guard: nothing in it moves a host value to the device implicitly."""
+    from repro.core.client import ClientRunner
+    from repro.core.freezing import count_params
+    from repro.core.policy import Knobs
+    from repro.core.resources import calibrate
+    from repro.data.federated import FederatedData
+    from repro.fl import ClientInfo, DeviceProfile, make_executor
+    ds = load_corpus(target_bytes=60_000)
+    cfg = get_config("charlm-shakespeare").replace(
+        vocab_size=max(ds.vocab_size, 64), num_layers=2, d_model=32,
+        num_heads=4, num_kv_heads=4, head_dim=8, d_ff=64)
+    fl = get_fl_config().replace(num_clients=4, seq_len=16, wire_topk=topk)
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    resources = calibrate(count_params(params), fl)
+    data = FederatedData(ds.train, fl.num_clients, seed=fl.seed)
+    ex = make_executor("batched", ClientRunner(model, fl, data, resources))
+    profile = DeviceProfile("default", fl.budgets, resources=resources)
+    rounds = [[(ClientInfo(c, profile, 1), Knobs(k=1, s=2, b=4, q=q,
+                                                 grad_accum=2))
+               for c in range(3)] for q in (1, 2)]
+    for assignments in rounds:          # warm: masks, programs
+        ex.run_round(params, assignments)
+    with no_transfers():
+        for assignments in rounds:
+            outs = ex.run_round(params, assignments)
+            assert all(np.isfinite(o.train_loss) for o in outs)

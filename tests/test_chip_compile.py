@@ -3,9 +3,10 @@
 Ahead-of-time compiles against a described ``v5e:2x2`` topology (no
 chip attached): the chip's own compiler refuses what interpret mode
 accepts — misaligned blocks, unsigned reductions, layouts Mosaic cannot
-build — so the wire kernels are compiled at the char-LM's largest leaf,
-and the batched executor's round program at the paper's configuration
-from ``jax.eval_shape`` shapes.
+build — so the wire kernels are compiled at the char-LM's largest leaf
+and at a whole cohort's packed blocks (the batched executor ships a
+knob group in one launch per kernel), and the batched executor's round
+program at the paper's configuration from ``jax.eval_shape`` shapes.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library, and
@@ -69,6 +70,25 @@ def leaf_blocks(paper):
     return -(-n // tile) * tile // BLOCK
 
 
+@pytest.fixture(scope="module")
+def cohort(one_chip):
+    """The stacked deltas of a 6-client cohort of the paper's char-LM at
+    its stated widths (d=256, MLP 4 x 256: 4,896,768 parameters), on
+    the chip, and the wire blocks they pack into."""
+    from repro.configs import get_config
+    from repro.core import compression
+    from repro.models import build
+    cfg = get_config("charlm-shakespeare").replace(
+        d_model=256, head_dim=32, d_ff=1024)
+    params = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
+    assert sum(math.prod(l.shape)
+               for l in jax.tree.leaves(params)) == 4_896_768
+    stacked = jax.tree.map(
+        lambda l: _sds((COHORT,) + l.shape, jnp.float32, one_chip), params)
+    blocks = jax.eval_shape(compression.pack_stacked, stacked)
+    return stacked, blocks.shape[0]
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -99,6 +119,39 @@ def test_quantize_topk_blocks_compiles(one_chip, leaf_blocks):
     text = _compiled_text(
         lambda t: wk.quantize_topk_blocks(t, 2, TOPK, interpret=False), x)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("bits", [8, 2])
+def test_cohort_wire_kernels_compile(bits, one_chip, cohort):
+    """Quantize and dequantize over a whole cohort's packed blocks,
+    about 115k blocks of 256: one launch each per knob group."""
+    _, rows = cohort
+    assert rows % qk.ROWS_PER_TILE == 0 and 110_000 < rows < 120_000
+    x = _sds((rows, BLOCK), jnp.float32, one_chip)
+    text = _compiled_text(
+        lambda t: qk.quantize_blocks(t, bits, interpret=False), x)
+    assert "tpu_custom_call" in text
+    codes = _sds((rows, BLOCK), jnp.int8, one_chip)
+    scales = _sds((rows,), jnp.float32, one_chip)
+    text = _compiled_text(
+        lambda c, s: qk.dequantize_blocks(c, s, interpret=False),
+        codes, scales)
+    assert "tpu_custom_call" in text
+
+
+def test_cohort_pack_and_unpack_compile(one_chip, cohort):
+    """The programs around the kernels: pack the stacked deltas, and
+    unpack the round-tripped blocks into the masked per-client trees."""
+    from repro.core import compression
+    stacked, rows = cohort
+    packed = compression.pack_stacked.lower(stacked).compile()
+    assert packed.out_info.shape == (rows, BLOCK)
+    mask = jax.tree.map(lambda l: _sds((), jnp.float32, one_chip), stacked)
+    leaves, treedef = jax.tree.flatten(stacked)
+    unpack = compression._unpack.lower(
+        _sds((rows, BLOCK), jnp.float32, one_chip), mask, treedef=treedef,
+        shapes=tuple(l.shape for l in leaves), block=BLOCK).compile()
+    assert len(unpack.out_info) == COHORT
 
 
 def test_masked_sum_limbs_compiles(one_chip, leaf_blocks):

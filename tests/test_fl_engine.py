@@ -150,6 +150,63 @@ def test_batched_groups_mixed_knobs(tiny_setup, tiny_model):
     assert all(np.isfinite(o.train_loss) for o in outs)
 
 
+@pytest.mark.parametrize("topk", [None, 64])
+def test_batched_and_sequential_ship_the_same_cafl_round(tiny_setup,
+                                                         tiny_model, topk):
+    """A CAFL-L round at q=2 (freezing, grad accumulation, the 2-bit
+    wire, dense or top-64): both executors ship the same deltas and the
+    same wire MB. LocalTrain's float reassociation may flip a rare code
+    by one step; the wire stage adds no difference: on the batched
+    LocalTrain's own deltas the group's stacked wire ships exactly what
+    the per-client, per-leaf round trip ships."""
+    import jax
+    from repro.core import compression, freezing
+    from repro.core.client import ClientRunner
+    from repro.core.freezing import count_params
+    from repro.core.policy import Knobs
+    from repro.core.resources import calibrate
+    from repro.data.federated import FederatedData
+    from repro.fl import executor
+
+    ds, _, fl = tiny_setup
+    fl = fl.replace(wire_topk=topk)
+    params = tiny_model.init(jax.random.PRNGKey(0))
+    resources = calibrate(count_params(params), fl)
+    profile = DeviceProfile("default", fl.budgets, resources=resources)
+    kn = Knobs(k=2, s=2, b=4, q=2, grad_accum=2)
+    assignments = [(ClientInfo(c, profile, 1), kn) for c in range(3)]
+
+    def runner():
+        data = FederatedData(ds.train, fl.num_clients, seed=fl.seed)
+        return ClientRunner(tiny_model, fl, data, resources)
+
+    seq = make_executor("sequential", runner()).run_round(params, assignments)
+    ex = make_executor("batched", runner())
+    bat = ex.run_round(params, assignments)
+    for a, b in zip(seq, bat):
+        assert a.client_id == b.client_id
+        assert a.wire_mb_actual == b.wire_mb_actual
+        assert a.params_active == b.params_active
+        for x, y in zip(jax.tree.leaves(a.delta), jax.tree.leaves(b.delta)):
+            x, y = np.asarray(x), np.asarray(y)
+            assert np.mean(x != y) <= 1e-3
+            assert np.max(np.abs(x - y), initial=0.0) <= np.max(
+                np.abs(x), initial=0.0)
+
+    # the wire stage alone, on the batched LocalTrain's raw deltas
+    fresh = runner()
+    mask, _ = fresh.mask_for(params, kn.k)
+    batches = executor.BatchedExecutor(fresh)._stack_batches([0, 1, 2], kn)
+    raw, _ = ex._batched(params, mask, batches)
+    want = [freezing.apply_mask(compression.compress_decompress(
+        jax.tree.map(lambda l, i=i: l[i], raw), kn.q, topk=topk), mask)
+        for i in range(3)]
+    got = executor._compress(raw, mask, kn.q, topk=topk)
+    for g, w in zip(got, want):
+        for x, y in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_make_executor_unknown():
     with pytest.raises(ValueError):
         make_executor("warp", None)

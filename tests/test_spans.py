@@ -113,27 +113,27 @@ def test_off_the_profiler_records_and_counts_nothing(unprofiled):
 
 
 def _check_rounds(run: Run, launches: int) -> None:
-    leaves = len(jax.tree.leaves(
-        jax.eval_shape(run.engine.model.init, jax.random.PRNGKey(0))))
+    """Every q > 0 knob group makes one stage, LocalTrain, wire round
+    trip (``launches`` kernel launches over the group's packed blocks),
+    unstack and wire-byte count."""
     groups = run.groups
     assert [r.round for r in run.rounds] == list(
         range(1, run.engine.fl.rounds + 1))
     for rnd in run.rounds:
         assert rnd.complete
-        clients = groups.clients[rnd.round]
+        n_groups = groups.groups[rnd.round]
         assert 0 not in groups.q[rnd.round]
         assert Counter(s.name for s in rnd.spans) == {
             **{n: 1 for n in NESTING},
-            "stage": groups.groups[rnd.round],
-            "local_train_wait": groups.groups[rnd.round],
-            "unstack": clients, "wire": clients, "wire_bytes": clients}
+            **{n: n_groups for n in ("stage", "local_train_wait", "unstack",
+                                     "wire", "wire_bytes")}}
         for s in rnd.spans:
             assert s.round == rnd.round
             assert s.parent == NESTING[s.name]
             assert s.start_ns <= s.end_ns
         assert rnd.counters == {
-            "wire_calls": clients * leaves * launches,
-            "localtrain_calls": groups.groups[rnd.round]}
+            "wire_calls": n_groups * launches,
+            "localtrain_calls": n_groups}
         # execute's children lie inside it, one after another
         (execute,) = [s for s in rnd.spans if s.name == "execute"]
         inner = sorted((s for s in rnd.spans if s.parent == "execute"),
@@ -144,7 +144,7 @@ def _check_rounds(run: Run, launches: int) -> None:
 
 
 def test_profiled_run_records_every_span_and_count(profiled):
-    """Off the TPU the wire runs the ref twin: one launch per leaf."""
+    """Off the TPU the wire runs the ref twin: one launch per group."""
     _check_rounds(profiled, launches=1)
 
 
