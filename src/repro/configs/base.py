@@ -81,6 +81,9 @@ class ModelConfig:
     final_softcap: Optional[float] = None
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # share of each head's dims that rotary rotates (Nemotron: 0.5); the
+    # rest pass through unrotated
+    rope_fraction: float = 1.0
     # decode-time sliding window for long-context shapes (sub-quadratic
     # variant; None -> full cache)
     decode_window: Optional[int] = 8192
@@ -97,8 +100,10 @@ class ModelConfig:
     # --- frontend stub ---
     frontend: Optional[FrontendConfig] = None
     # --- misc ---
-    mlp_type: str = "swiglu"          # swiglu | geglu | gelu | none
+    mlp_type: str = "swiglu"          # swiglu | geglu | gelu | relu2 | none
+    mlp_bias: bool = True             # biases of the ungated (gelu, relu2) MLP
     norm_type: str = "rms"            # rms | layer
+    norm_eps: float = 1e-6
     post_norms: bool = False          # gemma2-style post-attn/post-ffn norms
     tie_embeddings: bool = True
     embed_scale: bool = False         # gemma multiplies embeddings by sqrt(d)

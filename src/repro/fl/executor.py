@@ -87,7 +87,9 @@ class BatchedExecutor(ClientExecutor):
             (gsum, lsum), _ = jax.lax.scan(
                 accum, (zeros, jnp.zeros((), jnp.float32)), micros)
             grads = jax.tree.map(lambda g: g / ga, gsum)
-            w, opt_state = apply_masked_update(opt, w, opt_state, grads, mask)
+            with jax.named_scope("masked_update"):
+                w, opt_state = apply_masked_update(opt, w, opt_state, grads,
+                                                   mask)
             return (w, opt_state), lsum / ga
 
         opt_state = opt.init(params)

@@ -91,11 +91,11 @@ def norm_apply(p, x, cfg: ModelConfig):
     if cfg.norm_type == "layer":
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)
-        out = (xf - mu) * jax.lax.rsqrt(var + 1e-6)
+        out = (xf - mu) * jax.lax.rsqrt(var + cfg.norm_eps)
         out = out * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     else:
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(var + 1e-6)
+        out = xf * jax.lax.rsqrt(var + cfg.norm_eps)
         out = out * (1.0 + p["scale"].astype(jnp.float32))
     return out.astype(x.dtype)
 
@@ -105,9 +105,21 @@ def norm_apply(p, x, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def rope(x, positions, theta: float):
-    """x: (..., S, H, D) with D even; positions: (..., S)."""
+def rope(x, positions, theta: float, fraction: float = 1.0):
+    """x: (..., S, H, D) with D even; positions: (..., S).
+
+    ``fraction`` < 1 rotates the first ``fraction * D`` dims (half-split
+    within them, frequencies over that width) and passes the rest
+    through, as Nemotron's partial rotary does."""
     d = x.shape[-1]
+    rot = int(d * fraction)
+    if rot != d:
+        if rot <= 0 or rot % 2:
+            raise ValueError(f"rope_fraction {fraction} of head dim {d} "
+                             f"gives {rot} rotary dims; need a positive "
+                             f"even number")
+        return jnp.concatenate([rope(x[..., :rot], positions, theta),
+                                x[..., rot:]], axis=-1)
     half = d // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., :, None].astype(jnp.float32) * freq  # (..., S, half)
@@ -131,10 +143,12 @@ def mlp_init(rng, cfg: ModelConfig, d_ff: Optional[int] = None, d_model: Optiona
         return {"w_gate": dense_init(r[0], dm, d_ff, cfg.param_dtype),
                 "w_up": dense_init(r[1], dm, d_ff, cfg.param_dtype),
                 "w_down": dense_init(r[2], d_ff, dm, cfg.param_dtype)}
-    return {"w_up": dense_init(r[0], dm, d_ff, cfg.param_dtype),
-            "b_up": jnp.zeros((d_ff,), cfg.param_dtype),
-            "w_down": dense_init(r[1], d_ff, dm, cfg.param_dtype),
-            "b_down": jnp.zeros((dm,), cfg.param_dtype)}
+    p = {"w_up": dense_init(r[0], dm, d_ff, cfg.param_dtype),
+         "w_down": dense_init(r[1], d_ff, dm, cfg.param_dtype)}
+    if cfg.mlp_bias:
+        p["b_up"] = jnp.zeros((d_ff,), cfg.param_dtype)
+        p["b_down"] = jnp.zeros((dm,), cfg.param_dtype)
+    return p
 
 
 def mlp_apply(p, x, cfg: ModelConfig):
@@ -142,12 +156,15 @@ def mlp_apply(p, x, cfg: ModelConfig):
         g = x @ p["w_gate"]
         act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
         return (act * (x @ p["w_up"])) @ p["w_down"]
-    h = x @ p["w_up"] + p["b_up"]
+    h = x @ p["w_up"]
+    if cfg.mlp_bias:
+        h = h + p["b_up"]
     if cfg.mlp_type == "relu2":                     # nemotron squared-ReLU
         h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h)
-    return h @ p["w_down"] + p["b_down"]
+    out = h @ p["w_down"]
+    return out + p["b_down"] if cfg.mlp_bias else out
 
 
 # ---------------------------------------------------------------------------
@@ -333,8 +350,9 @@ def attn_apply_full(p, x, positions, cfg: ModelConfig, *, window=None):
     """Training / prefill forward (no cache in, optionally cache out)."""
     b, s, _ = x.shape
     q, k, v = _qkv(p, x, cfg)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
     out = blockwise_attention(q, k, v, window=window, softcap=cfg.attn_softcap,
                               q_chunk=cfg.q_chunk)
     return out.reshape(b, s, -1) @ p["wo"], (k, v)
@@ -346,8 +364,8 @@ def attn_apply_decode(p, x, cache, cfg: ModelConfig, *, window=None):
     q, k, v = _qkv(p, x, cfg)
     idx = cache["index"]
     pos = jnp.full((b, 1), idx, jnp.int32)
-    q = rope(q, pos, cfg.rope_theta)
-    k = rope(k, pos, cfg.rope_theta)
+    q = rope(q, pos, cfg.rope_theta, cfg.rope_fraction)
+    k = rope(k, pos, cfg.rope_theta, cfg.rope_fraction)
     s_buf = cache["k"].shape[1]
     slot = idx % s_buf
     k_cache = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),

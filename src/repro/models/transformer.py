@@ -112,16 +112,20 @@ def block_apply_full(p, x, positions, cfg: ModelConfig, spec: BlockSpec,
     aux = 0.0
     if spec.kind == "attn":
         h = L.norm_apply(p["ln1"], x, cfg)
-        if cfg.mla:
-            a, (c_kv, k_rope) = L.mla_apply_full(p["attn"], h, positions, cfg)
-        else:
-            a, (k, v) = L.attn_apply_full(p["attn"], h, positions, cfg,
-                                          window=spec.window)
+        # scope names reach the device trace's op metadata only
+        with jax.named_scope("attention"):
+            if cfg.mla:
+                a, (c_kv, k_rope) = L.mla_apply_full(p["attn"], h, positions,
+                                                     cfg)
+            else:
+                a, (k, v) = L.attn_apply_full(p["attn"], h, positions, cfg,
+                                              window=spec.window)
         if cfg.post_norms:
             a = L.norm_apply(p["post1"], a, cfg)
         x = x + a
         h = L.norm_apply(p["ln2"], x, cfg)
-        f, aux = _ffn(p, h, cfg, spec)
+        with jax.named_scope("mlp"):
+            f, aux = _ffn(p, h, cfg, spec)
         if cfg.post_norms:
             f = L.norm_apply(p["post2"], f, cfg)
         x = x + f

@@ -155,13 +155,15 @@ def _decoder_model(cfg: ModelConfig) -> Model:
         x, positions = _embed_batch(params, batch)
         x, _, aux = T.stack_apply_full(params["stack"], x, positions, cfg,
                                        want_cache=False, remat=True)
-        x = L.norm_apply(params["io"]["final_norm"], x, cfg)
-        if n_prefix_tok:
-            x = x[:, n_prefix_tok:]
-        targets = batch["targets"]
-        mask = batch.get("loss_mask", jnp.ones(targets.shape, jnp.float32))
-        w = unembed_matrix(params["io"], cfg).astype(cfg.compute_dtype)
-        ce = chunked_ce_loss(x, w, targets, mask, cfg.final_softcap)
+        with jax.named_scope("head_loss"):
+            x = L.norm_apply(params["io"]["final_norm"], x, cfg)
+            if n_prefix_tok:
+                x = x[:, n_prefix_tok:]
+            targets = batch["targets"]
+            mask = batch.get("loss_mask",
+                             jnp.ones(targets.shape, jnp.float32))
+            w = unembed_matrix(params["io"], cfg).astype(cfg.compute_dtype)
+            ce = chunked_ce_loss(x, w, targets, mask, cfg.final_softcap)
         return ce + aux, {"ce": ce, "aux": aux}
 
     def prefill(params, batch, use_decode_window: bool = False,
