@@ -19,6 +19,7 @@ from repro.constraints import (  # noqa: F401
     make_controller, make_knob_policy, paper_constraints,
     register_constraint,
 )
+from repro import spans  # noqa: F401  (``from repro.fl import spans``)
 from repro.core.client import ClientResult, ClientRunner  # noqa: F401
 from repro.core.server import FLResult, RoundRecord  # noqa: F401
 from repro.fl.aggregator import (  # noqa: F401

@@ -266,8 +266,10 @@ class Aggregator:
 
 class SyncAggregator(Aggregator):
     """The paper's round barrier: buffer every report of the round and
-    apply one combined update at ``flush``. Default; stream- and
-    bit-identical to the PR 1/2 engine (golden trajectories pin it)."""
+    apply one combined update at ``flush``. Default. Its updates match
+    the original monolithic loop's eager fold to one ulp per element,
+    the compiled fold's fused multiply-add being the only difference
+    (golden trajectories pin it within their tolerances)."""
 
     name = "sync"
     commutativity = "canonical"

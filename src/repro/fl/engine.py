@@ -57,7 +57,7 @@ The engine runs in one of two *time modes* (``repro.fl.clock``):
                             seconds budget.
 
 While a profiler trace records, each round is a host span
-(``repro.fl.spans``): ``round`` around its body and, inside it,
+(``repro.spans``): ``round`` around its body and, inside it,
 ``eval``, ``compose``, ``execute`` (the executor), ``report``,
 ``aggregate``, ``accounting`` and ``dual_update``.
 
@@ -81,7 +81,7 @@ from repro.core.resources import ResourceModel, calibrate
 from repro.core.server import FLResult, RoundRecord, make_eval_fn
 from repro.data.federated import FederatedData
 from repro.data.shakespeare import CharDataset
-from repro.fl import spans
+from repro import spans
 from repro.fl.aggregator import (Aggregator, ClientReport, ServerUpdate,
                                  canonical_order, make_aggregator)
 from repro.fl.callbacks import RoundCallback

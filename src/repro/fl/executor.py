@@ -13,7 +13,7 @@ intermediate host sync. The group's wire round trip then runs over the
 stacked deltas on the device too: one pack, one quantize and one
 dequantize for the whole group, and one program that hands back the
 per-client trees. Under the profiler its host work is spans inside the
-engine's ``execute`` (``repro.fl.spans``), each once per knob group:
+engine's ``execute`` (``repro.spans``), each once per knob group:
 ``stage``, ``local_train_wait``, ``wire`` (pack and round trip; none
 at q=0), ``unstack`` (per-client trees and freeze mask) and
 ``wire_bytes``; ``localtrain_calls`` counts the launches.
@@ -29,7 +29,7 @@ import numpy as np
 from repro.core.client import (ClientResult, ClientRunner,
                                _masked_wire_mb, apply_masked_update)
 from repro.core.policy import Knobs
-from repro.fl import spans
+from repro import spans
 from repro.fl.device import ClientInfo
 
 Assignment = Tuple[ClientInfo, Knobs]

@@ -10,7 +10,7 @@ one place that chooses ``interpret``: every kernel takes it as a
 required keyword. While the profiler records, each wire-kernel launch
 (a quantize, a dequantize, or a ref twin's whole round trip, whether
 over one tensor or over a knob group's packed blocks) adds one to the
-round's ``wire_calls`` counter (``repro.fl.spans``).
+round's ``wire_calls`` counter (``repro.spans``).
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl import spans
+from repro import spans
 from repro.kernels import ref
 from repro.kernels import quantize as qk
 from repro.kernels import wire as wk

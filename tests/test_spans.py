@@ -1,10 +1,10 @@
-"""Host spans and counters of the federated round (``repro.fl.spans``).
+"""Host spans and counters of the federated round (``repro.spans``).
 
 Off the profiler nothing is recorded. Under ``jax.profiler.trace`` a
 tiny CAFL-L run records every span of the round, nested where the work
-happens, counts each wire-kernel and LocalTrain launch, writes the same
-spans into the profiler's own trace on a host plane, and leaves the
-run's records as they are without the profiler."""
+happens, counts each wire-kernel, LocalTrain and aggregation launch,
+writes the same spans into the profiler's own trace on a host plane,
+and leaves the run's records as they are without the profiler."""
 import dataclasses
 from collections import Counter
 
@@ -133,7 +133,8 @@ def _check_rounds(run: Run, launches: int) -> None:
             assert s.start_ns <= s.end_ns
         assert rnd.counters == {
             "wire_calls": n_groups * launches,
-            "localtrain_calls": n_groups}
+            "localtrain_calls": n_groups,
+            "aggregate_calls": groups.clients[rnd.round] + 1}
         # execute's children lie inside it, one after another
         (execute,) = [s for s in rnd.spans if s.name == "execute"]
         inner = sorted((s for s in rnd.spans if s.parent == "execute"),
